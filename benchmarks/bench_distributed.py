@@ -78,7 +78,6 @@ _SCRIPT = """
     # jaxpr) while returning bitwise-identical results; wall-clock is
     # reported per schedule and the overlapped run must not regress.
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.core import AffinitySpec
     from repro.core.operators import sharded_streaming_operator
 
@@ -110,8 +109,8 @@ _SCRIPT = """
             for _ in range(sweeps):
                 u = op.matmat(u)
             return u
-        f = shard_map(fn, mesh=mesh, in_specs=(P("data"), P("data")),
-                      out_specs=P("data"), check_rep=False)
+        f = jax.shard_map(fn, mesh=mesh, in_specs=(P("data"), P("data")),
+                          out_specs=P("data"), check_vma=False)
         return count_pp(jax.make_jaxpr(f)(xs, v).jaxpr)
 
     pp_ovl = sweep_pp(True, 2) - sweep_pp(True, 1)

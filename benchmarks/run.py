@@ -41,6 +41,9 @@ def main() -> None:
                     help="write a JSON perf snapshot (default BENCH_PR10.json)")
     args = ap.parse_args()
 
+    from repro.compile_cache import configure_compile_cache
+    configure_compile_cache()
+
     from . import (bench_affinity, bench_distributed, bench_exp2, bench_fig3,
                    bench_multivec, bench_quality, bench_robustness,
                    bench_table1, bench_table2, roofline)

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import configure_compile_cache
 from repro.configs import TrainConfig, get_config
 from repro.core import gpic_matrix_free
 from repro.data.tokens import SyntheticTokenStream
@@ -20,6 +21,7 @@ from repro.train import adamw_init, build_train_step
 
 
 def main():
+    configure_compile_cache()
     cfg = get_config("stablelm-3b").replace(
         n_layers=4, d_model=256, n_heads=4, n_kv_heads=4, head_dim=64,
         d_ff=704, vocab_size=2048)

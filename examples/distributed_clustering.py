@@ -1,21 +1,27 @@
 """Distributed GPIC on a multi-device mesh (the paper's multi-GPU future
 work, realized with shard_map over the operator pipeline — DESIGN.md §9).
 
-Runs on 8 virtual CPU devices; the identical code shards over the
-(pod, data) axes of the production mesh on real hardware. All three
+The mesh spans every device JAX finds: the chips of an accelerator host,
+or — with ``JAX_PLATFORMS=cpu`` — 8 virtual CPU devices. All three
 sharded paths run the SAME convergence engine as the single-device
 entry points — only the PowerOperator binding changes.
 
     PYTHONPATH=src python examples/distributed_clustering.py
+    PYTHONPATH=src JAX_PLATFORMS=cpu python examples/distributed_clustering.py
 """
 import os
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+if os.environ.get("JAX_PLATFORMS") == "cpu":
+    # virtual host devices only where the run is pinned to the CPU; on an
+    # accelerator host the mesh is made of its chips
+    os.environ.setdefault("XLA_FLAGS",
+                          "--xla_force_host_platform_device_count=8")
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+from repro.compile_cache import configure_compile_cache  # noqa: E402
 from repro.core import (  # noqa: E402
     GPICConfig, adjusted_rand_index, pic_reference, run_gpic)
 from repro.core.distributed import shard_points  # noqa: E402
@@ -23,8 +29,9 @@ from repro.data import dataset_by_name  # noqa: E402
 
 
 def main():
-    mesh = jax.make_mesh((8,), ("data",))
-    print(f"mesh: {mesh.shape}")
+    configure_compile_cache()
+    mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+    print(f"mesh: {mesh.shape} on {jax.devices()[0].device_kind}")
 
     # explicit path: row-striped Pallas A build, O(n r) collectives per step
     x, y, k = dataset_by_name("three_circles", 1600, seed=0)

@@ -9,6 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import configure_compile_cache
 from repro.core import (
     AffinitySpec,
     GPICConfig,
@@ -20,6 +21,7 @@ from repro.data import dataset_by_name
 
 
 def main():
+    configure_compile_cache()
     print("GPIC quickstart — explicit-A (paper-faithful) pipeline")
     for name, sigma, nv in (("three_circles", 0.3, 1), ("cassini", 0.3, 2),
                             ("gaussians", 0.3, 1), ("smiley", 0.15, 1)):

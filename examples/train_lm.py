@@ -12,6 +12,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import configure_compile_cache
 from repro.configs import TrainConfig, get_config
 from repro.data.tokens import SyntheticTokenStream
 from repro.models import get_api
@@ -26,6 +27,7 @@ def main():
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_lm")
     args = ap.parse_args()
+    configure_compile_cache()
 
     # ~100M-param member of the stablelm family
     cfg = get_config("stablelm-3b").replace(

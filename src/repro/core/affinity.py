@@ -36,6 +36,8 @@ from typing import Literal
 import jax
 import jax.numpy as jnp
 
+from ..kernels.tuning import f32_matmul
+
 AffinityKind = Literal["cosine", "cosine_shifted", "rbf"]
 
 AFFINITY_KINDS = ("cosine", "cosine_shifted", "rbf")
@@ -177,7 +179,7 @@ def rbf_bandwidth_heuristic(x: jax.Array, sample: int = 512) -> jax.Array:
     d2 = (
         jnp.sum(s * s, axis=1)[:, None]
         + jnp.sum(s * s, axis=1)[None, :]
-        - 2.0 * s @ s.T
+        - 2.0 * f32_matmul(s, s.T)
     )
     d2 = jnp.maximum(d2, 0.0)
     med = jnp.median(jnp.sqrt(d2 + jnp.eye(s.shape[0]) * 1e9))
@@ -194,7 +196,8 @@ def pairwise_sq_dists(x: jax.Array, xc: jax.Array | None = None) -> jax.Array:
     c = x if xc is None else xc
     sqr = jnp.sum(x * x, axis=1)
     sqc = jnp.sum(c * c, axis=1)
-    return jnp.maximum(sqr[:, None] + sqc[None, :] - 2.0 * (x @ c.T), 0.0)
+    return jnp.maximum(
+        sqr[:, None] + sqc[None, :] - 2.0 * f32_matmul(x, c.T), 0.0)
 
 
 def local_scales(x: jax.Array, scale_k: int) -> jax.Array:
@@ -235,7 +238,7 @@ def affinity_matrix(
         spec.validate_for_n(x.shape[0])
         if spec.kind in ("cosine", "cosine_shifted"):
             xn = row_normalize_features(x)
-            a = xn @ xn.T
+            a = f32_matmul(xn, xn.T)
             if spec.kind == "cosine_shifted":
                 a = 0.5 * (1.0 + a)
         elif spec.adaptive:
@@ -253,14 +256,15 @@ def affinity_matrix(
 
     if kind in ("cosine", "cosine_shifted"):
         xn = row_normalize_features(x)
-        a = xn @ xn.T
+        a = f32_matmul(xn, xn.T)
         if kind == "cosine_shifted":
             a = 0.5 * (1.0 + a)
         return _zero_diag(a)
     if kind == "rbf":
         sig = rbf_bandwidth_heuristic(x) if sigma is None else jnp.asarray(sigma)
         sq = jnp.sum(x * x, axis=1)
-        d2 = jnp.maximum(sq[:, None] + sq[None, :] - 2.0 * (x @ x.T), 0.0)
+        d2 = jnp.maximum(
+            sq[:, None] + sq[None, :] - 2.0 * f32_matmul(x, x.T), 0.0)
         a = jnp.exp(-d2 / (2.0 * sig * sig))
         return _zero_diag(a)
     raise ValueError(f"unknown affinity kind {kind!r}")
@@ -283,7 +287,7 @@ def affinity_chunked(
         xn = x
 
         def stripe(xc, i0):
-            a = xc @ xn.T
+            a = f32_matmul(xc, xn.T)
             if kind == "cosine_shifted":
                 a = 0.5 * (1.0 + a)
             cols = jnp.arange(n)[None, :]
@@ -296,7 +300,8 @@ def affinity_chunked(
 
         def stripe(xc, i0):
             sqc = jnp.sum(xc * xc, axis=1)
-            d2 = jnp.maximum(sqc[:, None] + sq[None, :] - 2.0 * (xc @ x.T), 0.0)
+            d2 = jnp.maximum(
+                sqc[:, None] + sq[None, :] - 2.0 * f32_matmul(xc, x.T), 0.0)
             a = jnp.exp(-d2 / (2.0 * sig * sig))
             cols = jnp.arange(n)[None, :]
             rows = i0 + jnp.arange(xc.shape[0])[:, None]
@@ -344,10 +349,10 @@ def matmat_matrix_free(
     if psum is None:
         psum = lambda x: x
     if kind == "cosine":
-        return xn @ psum(xn.T @ v) - v
+        return f32_matmul(xn, psum(f32_matmul(xn.T, v))) - v
     if kind == "cosine_shifted":
         vsum = psum(jnp.sum(v, axis=0))
-        return 0.5 * (vsum + xn @ psum(xn.T @ v)) - v
+        return 0.5 * (vsum + f32_matmul(xn, psum(f32_matmul(xn.T, v)))) - v
     raise ValueError(f"matrix-free path supports cosine affinities, got {kind!r}")
 
 
@@ -431,7 +436,9 @@ def dense_block_live(a: jax.Array, tm: int, tn: int) -> jax.Array:
     n_rows, n_cols = a.shape
     rp = -(-n_rows // tm) * tm
     cp = -(-n_cols // tn) * tn
-    ap = jnp.pad(a, ((0, rp - n_rows), (0, cp - n_cols)))
+    ap = a
+    if (rp, cp) != a.shape:
+        ap = jnp.pad(a, ((0, rp - n_rows), (0, cp - n_cols)))
     tiles = ap.reshape(rp // tm, tm, cp // tn, tn)
     return jnp.any(tiles != 0, axis=(1, 3))
 
@@ -443,5 +450,4 @@ def invert_permutation(perm: jax.Array) -> jax.Array:
     rows (the row-reorder pass, core/graph.py). Pure index arithmetic, so
     applying it is exact: permute + inverse-permute is the identity on
     bits."""
-    return jnp.zeros_like(perm).at[perm].set(
-        jnp.arange(perm.shape[0], dtype=perm.dtype))
+    return jnp.argsort(perm).astype(perm.dtype)

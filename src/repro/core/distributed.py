@@ -35,6 +35,13 @@ every column reproduces its dedicated single-vector trajectory.
 
 The final k-means runs on the (gathered, replicated) (n, r) embedding
 identically on every device — deterministic, no collective needed.
+
+Every body runs under ``jax.shard_map(..., check_vma=False)``: the loops
+(power engine, k-means) seed their carries from replicated constants that
+become device-varying after the first sweep, and the gathered embedding
+is typed varying, so the varying-axes checker would need a ``pcast`` on
+every such carry. The outputs declared replicated (``P()``) are
+replicated by construction — they come out of psum/all_gather.
 """
 from __future__ import annotations
 
@@ -44,7 +51,6 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .affinity import AffinityKind, AffinitySpec, as_affinity_spec
@@ -254,11 +260,11 @@ def distributed_gpic(
                             force_reference=not use_pallas,
                             probe=probe_components and spec.truncated)
 
-    out = shard_map(
+    out = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(axes), P(), P()),
         out_specs=(P(),) * 9,
-        check_rep=False,
+        check_vma=False,
     )(x, kkm, u0t)
     labels, v, emb_full, t_cols, done, status, iso, n_comp, comp = out
     health = HealthReport(col_status=status, isolated_rows=iso,
@@ -319,11 +325,11 @@ def distributed_gpic_matrix_free(
                             residual_tol=residual_tol,
                             force_reference=not use_pallas)
 
-    out = shard_map(
+    out = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(axes), P(), P()),
         out_specs=(P(),) * 9,
-        check_rep=False,
+        check_vma=False,
     )(x, kkm, u0t)
     labels, v, emb_full, t_cols, done, status, iso, n_comp, comp = out
     health = HealthReport(col_status=status, isolated_rows=iso,
@@ -410,11 +416,11 @@ def distributed_gpic_segment_start(
         iso = count_bad_rows(op.degree, sum_fn=op.sum)
         return carry, iso
 
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(axes), P(), P()),
         out_specs=(_carry_specs(axes), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, u0t, stop)
 
 
@@ -458,11 +464,11 @@ def distributed_gpic_segment(
             op, carry_loc, eps, stop, mode=mode, qr_every=qr_every,
             snapshot_iters=snapshot_iters, residual_tol=residual_tol)
 
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(axes), _carry_specs(axes), P()),
         out_specs=_carry_specs(axes),
-        check_rep=False,
+        check_vma=False,
     )(x, carry, stop)
 
 
@@ -533,11 +539,11 @@ def distributed_gpic_segment_finalize(
         return labels, v_full, emb_full, t_cols, done, status, n_comp, \
             comp_full
 
-    out = shard_map(
+    out = jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(axes), _carry_specs(axes), P()),
         out_specs=(P(),) * 8,
-        check_rep=False,
+        check_vma=False,
     )(x, carry, key)
     labels, v, emb_full, t_cols, done, status, n_comp, comp = out
     health = HealthReport(col_status=status,
@@ -583,11 +589,11 @@ def distributed_component_ids(
             op, n, row_offset=idx * n_loc, max_components=max_components)
         return n_comp, op.all_gather(comp_loc)
 
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(axes),),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(x)
 
 

@@ -77,7 +77,8 @@ def fused_affinity_build(
     row_offset: jax.Array | int = 0,
     col_offset: jax.Array | int = 0,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """(A, D, thr) one-pass truncated build for the explicit engines
+    """(A, D, thr) one-pass truncated build for the explicit engines — A at
+    its zero-padded storage shape (``ops.affinity_and_degree(padded=True)``)
     (DESIGN.md §13) — replaces pass 1b + the masked rebuild with ONE sweep
     over the feature blocks plus cheap epilogues:
 
@@ -107,25 +108,31 @@ def fused_affinity_build(
     and reuse them for the block plan and every sweep.
     """
     assert spec.truncated, "fused_affinity_build is the truncated-spec build"
+    n_rows = x.shape[0]
+    n_cols = n_rows if xc is None else xc.shape[0]
+    # A at its tile-padded storage shape (pad entries exact zeros): the
+    # sweeps consume it in place, so the engine stores one copy
     a_raw, _ = ops.affinity_and_degree(
         x, xc, spec=spec, scale_r=scale_r, scale_c=scale_c, thr=None,
         tm=tm, tn=tn, out_dtype=jnp.float32,
-        row_offset=row_offset, col_offset=col_offset,
+        row_offset=row_offset, col_offset=col_offset, padded=True,
         force_reference=not use_pallas,
     )
     thr = topk_thresholds_from_scores(
-        a_raw, k=spec.knn_k, row_offset=row_offset, col_offset=col_offset)
-    a_f32 = jnp.where(a_raw >= thr[:, None], a_raw, 0.0)
-    n_rows, n_cols = a_f32.shape
+        a_raw[:n_rows, :n_cols], k=spec.knn_k,
+        row_offset=row_offset, col_offset=col_offset)
+    thr_p = jnp.pad(thr, (0, a_raw.shape[0] - n_rows),
+                    constant_values=jnp.inf)
+    a_f32 = jnp.where(a_raw >= thr_p[:, None], a_raw, 0.0)
     _, tn_r = ops.resolve_tiles(
         n_cols, tm, tn, m=x.shape[1],
         a_bytes=jnp.dtype(jnp.float32).itemsize)
-    cp = -(-n_cols // tn_r) * tn_r
-    ap = jnp.pad(a_f32, ((0, 0), (0, cp - n_cols)))
+    cp = -(-a_f32.shape[1] // tn_r) * tn_r
+    ap = jnp.pad(a_f32, ((0, 0), (0, cp - a_f32.shape[1])))
     d = jnp.sum(ap[:, :tn_r], axis=1)
     for j in range(1, cp // tn_r):
         d = d + jnp.sum(ap[:, j * tn_r:(j + 1) * tn_r], axis=1)
-    return a_f32.astype(a_dtype), d, thr
+    return a_f32.astype(a_dtype), d[:n_rows], thr
 
 
 # ---------------------------------------------------------------------------

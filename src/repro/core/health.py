@@ -368,7 +368,10 @@ def validate_features(x, k: int, *, sanitize: bool = False):
                 "to zero-fill them (recorded in PICResult.health.notes)")
         x = jnp.nan_to_num(x, nan=0.0, posinf=0.0, neginf=0.0)
         notes.append(f"sanitized:{n_bad}_nonfinite_features")
-    if bool(jnp.all(x == x[0:1])):
+    # per-column extrema instead of comparing against row 0: a reduction
+    # needs no indexing, so it also runs on a row-sharded array whose mesh
+    # axes are Explicit (the default of ``jax.make_mesh``)
+    if bool(jnp.all(jnp.max(x, axis=0) == jnp.min(x, axis=0))):
         raise InvalidInputError(
             "all feature rows are identical — every pairwise affinity is "
             "equal and the power embedding is constant; clustering is "

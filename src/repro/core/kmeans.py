@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels import ops
+from ..kernels.tuning import f32_matmul
 
 
 def kmeans_plus_plus_init(key: jax.Array, x: jax.Array, k: int) -> jax.Array:
@@ -87,7 +88,7 @@ def kmeans(
                                        force_reference=force_reference)
         onehot = jax.nn.one_hot(assign, k, dtype=x.dtype)      # (n, k)
         counts = jnp.sum(onehot, axis=0)                        # (k,)
-        sums = onehot.T @ x                                     # (k, d)
+        sums = f32_matmul(onehot.T, x)                          # (k, d)
         empty = counts == 0
         # farthest-point reseed: i-th empty slot takes the i-th farthest
         # point (argsort is stable — deterministic under ties)

@@ -48,6 +48,7 @@ import jax.numpy as jnp
 
 from ..kernels import ops
 from ..kernels.row_topk import row_topk_merge
+from ..kernels.tuning import f32_matmul
 from .affinity import (
     AffinityKind,
     AffinitySpec,
@@ -163,12 +164,22 @@ def _gram_binding(use_pallas: bool):
 # ---------------------------------------------------------------------------
 
 
+def _transpose_partial(a, v):
+    """Aᵀ V over the stored (possibly tile-padded, zero-filled) A, for V
+    with one row per logical row of A: V is zero-padded to A's storage
+    rows, so the product reads the one stored copy in place. Returns the
+    (storage cols, r) partial."""
+    vp = jnp.pad(v.astype(jnp.float32),
+                 ((0, a.shape[0] - v.shape[0]), (0, 0)))
+    return f32_matmul(a.astype(jnp.float32).T, vp)
+
+
 def _dense_transpose_matmat(a):
     """Local Aᵀ V binding for explicit (stored-A) operators: positivity-only
     transpose product for the symmetrized reachability probe — plain jnp,
     probe-frequency work (a handful of matvecs), never the power sweep."""
     def matmat_t(v):
-        return a.astype(jnp.float32).T @ v.astype(jnp.float32)
+        return _transpose_partial(a, v)[:v.shape[0]]
     return matmat_t
 
 
@@ -226,7 +237,8 @@ def explicit_operator(inp, *, spec: AffinitySpec | None = None,
     scale, thr = affinity_stats(inp, spec, tile=tile, use_pallas=use_pallas)
     a, d = ops.affinity_and_degree(
         inp, spec=spec, scale_r=scale, scale_c=scale, thr=thr,
-        tm=tile, tn=tile, out_dtype=a_dtype, force_reference=not use_pallas,
+        tm=tile, tn=tile, out_dtype=a_dtype, padded=True,
+        force_reference=not use_pallas,
     )
 
     def matmat(v):
@@ -395,7 +407,7 @@ def sharded_explicit_operator(x_loc, *, axes,
         column sums, and the local rows are sliced back out. Positivity-
         only probe work — the O(n r) collective runs a handful of times."""
         def matmat_t(v_loc):
-            part = a_loc.astype(jnp.float32).T @ v_loc.astype(jnp.float32)
+            part = _transpose_partial(a_loc, v_loc)
             return jax.lax.dynamic_slice_in_dim(psum(part), row0, n_loc)
         return matmat_t
 
@@ -437,7 +449,7 @@ def sharded_explicit_operator(x_loc, *, axes,
     a_loc, d_raw = ops.affinity_and_degree(
         x_loc, x_full, kind=build_kind, sigma=spec.sigma,
         scale_r=scale_loc, scale_c=scale_full, thr=thr_loc,
-        tm=tile, tn=tile, out_dtype=a_dtype, row_offset=row0,
+        tm=tile, tn=tile, out_dtype=a_dtype, row_offset=row0, padded=True,
         force_reference=not use_pallas,
     )
 

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.tuning import f32_matmul
 from .affinity import (
     AffinityKind,
     AffinitySpec,
@@ -191,7 +192,7 @@ def pic_from_affinity(
     kkm, krand = jax.random.split(key)
     v0 = init_power_vectors(krand, d, n_vectors, dtype=a.dtype)
     v, t_cols, done, emb_raw, status = run_power_embedding(
-        lambda vv: w @ vv, v0, eps, max_iter, embedding=embedding,
+        lambda vv: f32_matmul(w, vv), v0, eps, max_iter, embedding=embedding,
         qr_every=qr_every, snapshot_iters=snapshot_iters,
         residual_tol=residual_tol)
     emb = standardize_columns(emb_raw)

@@ -383,6 +383,11 @@ def run_gpic(
         # the permutation is a function of row content, so a resumed
         # attempt recomputes the identical canonical array); every per-row
         # output is mapped back through ``inv`` at the end
+        if cfg.mesh is not None:
+            # scoring and the take are global row operations: replicate
+            # once (a mesh with Explicit axes, the default of
+            # ``jax.make_mesh``, refuses them on a row-sharded array)
+            x = jax.device_put(x, NamedSharding(cfg.mesh, PartitionSpec()))
         perm = _row_reorder_permutation(x, cfg, spec)
         inv = invert_permutation(perm)
         x = jnp.take(x, perm, axis=0)

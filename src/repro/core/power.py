@@ -50,6 +50,8 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 
+from ..kernels.tuning import f32_matmul
+
 from .health import COL_MAXITER, COL_NONFINITE, COL_STALLED, COL_ZERO
 
 EMBEDDINGS = ("pic", "orthogonal", "ensemble")
@@ -69,7 +71,7 @@ def _gram_jnp(v):
     """Local-chunk Gram VᵀV in f32 — the default (oracle-math) binding;
     operator builders rebind to the Pallas tall-skinny kernel."""
     v32 = v.astype(jnp.float32)
-    return v32.T @ v32
+    return f32_matmul(v32.T, v32)
 
 
 @dataclass(frozen=True)
@@ -165,7 +167,7 @@ def subspace_residual(op, v, u):
     gvv, gvu, guu = g[:r, :r], g[:r, r:], g[r:, r:]
     lam = jnp.linalg.solve(gvv, gvu)
     denom = jnp.trace(guu)
-    res2 = denom - jnp.trace(gvu.T @ lam)
+    res2 = denom - jnp.trace(f32_matmul(gvu.T, lam))
     rel = jnp.sqrt(jnp.maximum(res2, 0.0) / jnp.maximum(denom, 1e-30))
     # a singular Gram (columns momentarily aligned) solves to non-finite;
     # report "not converged" and let the next QR re-mix, mirroring the

@@ -44,6 +44,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .tuning import MXU_PRECISION
+
 
 def affinity_tile_transform(dot, sqr, sqc, *, kind: str,
                             inv_two_sigma_sq: float,
@@ -145,7 +147,8 @@ def _affinity_kernel(
     xr = xr_ref[...]                   # (TM, m) row slab
     xc = xc_ref[...]                   # (TN, m) col slab
     dot = jax.lax.dot_general(
-        xr, xc, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        xr, xc, (((1,), (1,)), ((), ())),
+        precision=MXU_PRECISION, preferred_element_type=jnp.float32
     )                                  # (TM, TN) on the MXU
 
     a = affinity_tile_transform(
@@ -195,7 +198,13 @@ def affinity_and_degree(
     scale_c: jax.Array | None = None,
     thr: jax.Array | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """Returns (A (R, C), D (R,)) for the affinity stripe of ``xn`` vs ``xc``.
+    """Returns (A, D (R,)) for the affinity stripe of ``xn`` vs ``xc``.
+
+    A comes at its padded storage shape (R, C rounded up to tm, tn
+    multiples); pad rows and columns are masked to exact zeros, so the
+    sweeps consume the array as it is and no (R, C) copy is ever sliced
+    out of it (``kernels.ops.affinity_and_degree`` slices for callers that
+    want the logical shape).
 
     ``xc=None`` is the square self-affinity (the paper's build): A is
     (n, n) and D its row sums. With ``xc`` given, A is the
@@ -260,4 +269,4 @@ def affinity_and_degree(
         ],
         interpret=interpret,
     )(*operands, *pol_ops)
-    return a[:n_rows, :n_cols], d[:n_rows, 0]
+    return a, d[:n_rows, 0]

@@ -53,6 +53,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from .affinity import policy_specs_and_operands, unpack_policy_refs
 from .streaming import _masked_tile
+from .tuning import MXU_PRECISION
 
 
 def _prefetch_policy_specs(scale_r, thr, *, tm, tn):
@@ -86,7 +87,7 @@ def _bs_matmat_kernel(cnt_ref, col_ref, a_ref, v_ref, d_ref, u_ref):
     a = a_ref[...].astype(jnp.float32)
     partial = jax.lax.dot_general(
         a, v_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=MXU_PRECISION, preferred_element_type=jnp.float32)
 
     @pl.when(j == 0)
     def _init():
@@ -117,16 +118,19 @@ def block_sparse_matmat(
     """U = (A @ V) / d visiting only the live blocks of the stored A.
 
     ``a`` is the (R, C) truncated matrix exactly as the dense path stores
-    it (zeros in-tile); the plan (``counts``/``col_idx``/``max_b``, from
-    core/affinity.py::block_plan over the same tile grid) tells each
-    row-block which column tiles survive. Bitwise-equal to
+    it (zeros in-tile), at its logical or its zero-padded storage shape
+    (see degree_normalized_matmat); the plan (``counts``/``col_idx``/
+    ``max_b``, from core/affinity.py::block_plan over the same tile grid)
+    tells each row-block which column tiles survive. Bitwise-equal to
     degree_normalized_matmat at matching (tm, tn).
     """
-    n_rows, n_cols = a.shape
+    n_rows, n_cols = d.shape[0], v.shape[0]
     r = v.shape[1]
-    rp = pl.cdiv(n_rows, tm) * tm
-    cp = pl.cdiv(n_cols, tn) * tn
-    ap = jnp.pad(a, ((0, rp - n_rows), (0, cp - n_cols)))
+    rp = pl.cdiv(a.shape[0], tm) * tm
+    cp = pl.cdiv(a.shape[1], tn) * tn
+    ap = a
+    if (rp, cp) != a.shape:
+        ap = jnp.pad(a, ((0, rp - a.shape[0]), (0, cp - a.shape[1])))
     vp = jnp.pad(v.astype(jnp.float32), ((0, cp - n_cols), (0, 0)))
     dp = jnp.pad(d.astype(jnp.float32), (0, rp - n_rows),
                  constant_values=1.0)[:, None]
@@ -187,7 +191,7 @@ def _bs_streaming_kernel(
                      adaptive=adaptive, truncate=truncate)
     partial = jax.lax.dot_general(
         a, v_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=MXU_PRECISION, preferred_element_type=jnp.float32)
 
     @pl.when(j == 0)
     def _init():

@@ -20,12 +20,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .tuning import MXU_PRECISION
+
 
 def _gram_kernel(v_ref, g_ref):
     i = pl.program_id(0)
     v = v_ref[...].astype(jnp.float32)                   # (TM, rp)
     partial = jax.lax.dot_general(
-        v, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        v, v, (((0,), (0,)), ((), ())),
+        precision=MXU_PRECISION, preferred_element_type=jnp.float32
     )                                                    # (rp, rp)
 
     @pl.when(i == 0)

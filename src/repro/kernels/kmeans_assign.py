@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .tuning import MXU_PRECISION
+
 
 def _assign_kernel(x_ref, c_ref, csq_ref, lab_ref, dist_ref, *, k: int):
     x = x_ref[...]                              # (TM, d)
@@ -23,7 +25,8 @@ def _assign_kernel(x_ref, c_ref, csq_ref, lab_ref, dist_ref, *, k: int):
 
     xx = jnp.sum(x * x, axis=1, keepdims=True)  # (TM, 1)
     xc = jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, c, (((1,), (1,)), ((), ())),
+        precision=MXU_PRECISION, preferred_element_type=jnp.float32
     )                                           # (TM, Kp)
     d2 = xx + csq - 2.0 * xc
 

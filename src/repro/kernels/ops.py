@@ -24,13 +24,14 @@ Tile sizes default to the static autotuner in kernels/tuning.py; pass
 Graceful degradation (DESIGN.md §12): every public wrapper guards its
 kernel dispatch — a Pallas lowering/compile failure (or a fault injected
 with ``forced_kernel_failure``) degrades that op to the 'reference' oracle
-for the rest of the process and records the reason in
-``kernel_fallbacks()``, which the pipeline surfaces as health notes.
+for the rest of the process, writes the reason to stderr and records it
+in ``kernel_fallbacks()``, which the pipeline surfaces as health notes.
 """
 from __future__ import annotations
 
 import contextlib
 import os
+import sys
 from typing import Callable
 
 import jax
@@ -154,7 +155,11 @@ def _guarded(op: str, kernel_thunk: Callable, ref_thunk: Callable):
     """Run the fused kernel; if it raises (Pallas lowering/compile failure
     or an injected fault), degrade to the jnp reference oracle, record the
     reason once, and keep serving the oracle for the rest of the process.
-    Same math, unfused HLO — a slow correct answer instead of a crash."""
+    Same math, unfused HLO — a slow correct answer instead of a crash.
+
+    The degradation is never silent: it is written to stderr when it
+    happens, recorded in ``kernel_fallbacks()``, and noted on the run's
+    health report; a chip run (``chip_smoke.py``) refuses any of them."""
     if op in _FALLBACKS:
         return ref_thunk()
     try:
@@ -163,6 +168,8 @@ def _guarded(op: str, kernel_thunk: Callable, ref_thunk: Callable):
         return kernel_thunk()
     except Exception as e:  # noqa: BLE001 — any lowering failure degrades
         _FALLBACKS[op] = f"{type(e).__name__}: {e}"
+        print(f"kernels.ops: {op} kernel failed and now runs its jnp "
+              f"reference ({_FALLBACKS[op]})", file=sys.stderr)
         return ref_thunk()
 
 
@@ -245,7 +252,7 @@ def _spec_kind_sigma(spec, kind: str, sigma: float) -> tuple[str, float]:
 def affinity_and_degree(xn, xc=None, *, kind="cosine_shifted", sigma=1.0,
                         spec=None, scale_r=None, scale_c=None, thr=None,
                         tm=None, tn=None, out_dtype=jnp.float32,
-                        row_offset=0, col_offset=0,
+                        row_offset=0, col_offset=0, padded=False,
                         force_reference=False, mode=None):
     """Fused A + D build (paper kernels 1-2). See kernels/affinity.py.
 
@@ -257,29 +264,40 @@ def affinity_and_degree(xn, xc=None, *, kind="cosine_shifted", sigma=1.0,
     arrays ``scale_r``/``scale_c`` (adaptive local scales) and ``thr``
     (per-row truncation thresholds) realize its policies in-tile
     (DESIGN.md §11).
+
+    ``padded=True`` returns A at its storage shape — rows and columns
+    rounded up to the tile multiples, the pad entries exact zeros — which
+    the sweep kernels consume without a copy: the engines store ONE
+    (n, n) array. The default slices out the logical (R, C) matrix.
     """
     kind, sigma = _spec_kind_sigma(spec, kind, sigma)
     mode = _resolve_mode(mode, force_reference)
+    n_rows = xn.shape[0]
+    n_cols = n_rows if xc is None else xc.shape[0]
+    tm_, tn_ = _tiles(max(n_rows, n_cols), tm, tn, m=xn.shape[1],
+                      a_bytes=jnp.dtype(out_dtype).itemsize)
 
     def _ref():
         a, deg = ref.affinity_and_degree_ref(
             xn, xc, kind=kind, sigma=sigma,
             row_offset=row_offset, col_offset=col_offset,
             scale_r=scale_r, scale_c=scale_c, thr=thr)
-        return a.astype(out_dtype), deg   # honor O4 storage dtype here too
+        a = a.astype(out_dtype)           # honor O4 storage dtype here too
+        if padded:
+            a = jnp.pad(a, ((0, -(-n_rows // tm_) * tm_ - n_rows),
+                            (0, -(-n_cols // tn_) * tn_ - n_cols)))
+        return a, deg
 
     if mode == "reference":
         return _ref()
-    n = max(xn.shape[0], xn.shape[0] if xc is None else xc.shape[0])
-    tm_, tn_ = _tiles(n, tm, tn, m=xn.shape[1],
-                      a_bytes=jnp.dtype(out_dtype).itemsize)
-    return _guarded("affinity_and_degree", lambda: dispatch(
+    a, deg = _guarded("affinity_and_degree", lambda: dispatch(
         "affinity_and_degree", mode)(
         xn, xc, kind=kind, sigma=sigma, tm=tm_, tn=tn_, out_dtype=out_dtype,
         row_offset=row_offset, col_offset=col_offset,
         scale_r=scale_r, scale_c=scale_c, thr=thr,
         interpret=_interpret(),
     ), _ref)
+    return (a, deg) if padded else (a[:n_rows, :n_cols], deg)
 
 
 def degree_normalized_matvec(a, v, d, *, tm=None, tn=None,
@@ -301,11 +319,14 @@ def degree_normalized_matmat(a, v, d, *, tm=None, tn=None,
 
     ``a`` may be a rectangular (R, C) row stripe of the global matrix (the
     sharded explicit path, DESIGN.md §9); d is the stripe's (R,) degrees.
+    ``a`` may also come at the padded storage shape of
+    ``affinity_and_degree(..., padded=True)``; tiles are resolved from the
+    logical shape (len(d), len(v)) either way.
     """
     mode = _resolve_mode(mode, force_reference)
     if mode == "reference":
         return ref.degree_normalized_matmat_ref(a, v, d)
-    tm_, tn_ = _tiles(max(a.shape), tm, tn, r=v.shape[1],
+    tm_, tn_ = _tiles(max(d.shape[0], v.shape[0]), tm, tn, r=v.shape[1],
                       a_bytes=a.dtype.itemsize)
     return _guarded("degree_normalized_matmat", lambda: dispatch(
         "degree_normalized_matmat", mode)(

@@ -40,6 +40,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .tuning import MXU_PRECISION
+
 
 def _power_step_kernel(a_ref, v_ref, d_ref, u_ref, *, nj: int):
     j = pl.program_id(1)
@@ -47,7 +49,8 @@ def _power_step_kernel(a_ref, v_ref, d_ref, u_ref, *, nj: int):
     a = a_ref[...].astype(jnp.float32)   # (TM, TN) tile of A (f32 or bf16)
     v = v_ref[...]                       # (TN, r) slice of V
     partial = jax.lax.dot_general(
-        a, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        a, v, (((1,), (0,)), ((), ())),
+        precision=MXU_PRECISION, preferred_element_type=jnp.float32
     )                                    # (TM, r)
 
     @pl.when(j == 0)
@@ -85,17 +88,19 @@ def degree_normalized_matmat(
 ) -> jax.Array:
     """U = (A @ V) / d[:, None], one fused HBM sweep of A for all r columns.
 
-    Shapes: a (R, C) [f32 or bf16 storage; R == C on the single-device
-    square sweep, R == n/P on a sharded row stripe], v (C, r), d (R,);
-    returns (R, r) f32. The single-vector ``degree_normalized_matvec`` is
-    the r=1 case.
+    Shapes: v (C, r), d (R,); a is (R, C) [f32 or bf16 storage; R == C
+    on the single-device square sweep, R == n/P on a sharded row stripe]
+    or that matrix at a larger, zero-filled storage shape — the padded
+    array the affinity build returns, consumed without a copy. Returns
+    (R, r) f32. The single-vector ``degree_normalized_matvec`` is the r=1
+    case.
     """
-    n_rows, n_cols = a.shape
+    n_rows, n_cols = d.shape[0], v.shape[0]
     r = v.shape[1]
-    rp = pl.cdiv(n_rows, tm) * tm
-    cp = pl.cdiv(n_cols, tn) * tn
-    if rp != n_rows or cp != n_cols:
-        a = jnp.pad(a, ((0, rp - n_rows), (0, cp - n_cols)))
+    rp = pl.cdiv(a.shape[0], tm) * tm
+    cp = pl.cdiv(a.shape[1], tn) * tn
+    if (rp, cp) != a.shape:
+        a = jnp.pad(a, ((0, rp - a.shape[0]), (0, cp - a.shape[1])))
     if cp != n_cols:
         v = jnp.pad(v, ((0, cp - n_cols), (0, 0)))
     if rp != n_rows:

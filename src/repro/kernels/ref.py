@@ -1,8 +1,15 @@
-"""Pure-jnp oracles for every Pallas kernel (the correctness references)."""
+"""Pure-jnp oracles for every Pallas kernel (the correctness references).
+
+Every product runs at HIGHEST precision (DESIGN.md §6): an oracle whose
+f32 matmul took one bf16 pass on the MXU would be taken for a wrong
+kernel.
+"""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from .tuning import f32_matmul as _mm
 
 
 def _affinity_scores_ref(
@@ -17,14 +24,15 @@ def _affinity_scores_ref(
     """Dense (R, C) similarity scores before any masking — the one place
     the reference similarity transform (fixed or adaptive bandwidth) lives."""
     if kind in ("cosine", "cosine_shifted"):
-        a = x @ c.T
+        a = _mm(x, c.T)
         if kind == "cosine_shifted":
             a = 0.5 * (1.0 + a)
         return a
     if kind == "rbf":
         sqr = jnp.sum(x * x, axis=1)
         sqc = jnp.sum(c * c, axis=1)
-        d2 = jnp.maximum(sqr[:, None] + sqc[None, :] - 2.0 * (x @ c.T), 0.0)
+        d2 = jnp.maximum(sqr[:, None] + sqc[None, :] - 2.0 * _mm(x, c.T),
+                         0.0)
         if scale_r is not None:
             return jnp.exp(-d2 / (scale_r.astype(jnp.float32)[:, None]
                                   * scale_c.astype(jnp.float32)[None, :]))
@@ -94,7 +102,8 @@ def row_topk_ref(
     elif stat == "neg_sqdist":
         sqr = jnp.sum(x * x, axis=1)
         sqc = jnp.sum(c * c, axis=1)
-        s = -jnp.maximum(sqr[:, None] + sqc[None, :] - 2.0 * (x @ c.T), 0.0)
+        s = -jnp.maximum(sqr[:, None] + sqc[None, :] - 2.0 * _mm(x, c.T),
+                         0.0)
     else:
         raise ValueError(f"unknown stat {stat!r}")
     grows = row_offset + jnp.arange(s.shape[0])[:, None]
@@ -117,15 +126,18 @@ def degree_normalized_matvec_ref(
     a: jax.Array, v: jax.Array, d: jax.Array
 ) -> jax.Array:
     """Oracle for kernels.power_step.degree_normalized_matvec."""
-    u = a.astype(jnp.float32) @ v.astype(jnp.float32)
+    a = a[:d.shape[0], :v.shape[0]]      # padded storage → logical shape
+    u = _mm(a.astype(jnp.float32), v.astype(jnp.float32))
     return _floored_degree_divide(u, d)
 
 
 def degree_normalized_matmat_ref(
     a: jax.Array, v: jax.Array, d: jax.Array
 ) -> jax.Array:
-    """Oracle for kernels.power_step.degree_normalized_matmat (v is (n, r))."""
-    u = a.astype(jnp.float32) @ v.astype(jnp.float32)
+    """Oracle for kernels.power_step.degree_normalized_matmat (v is (n, r));
+    ``a`` may be at its zero-padded storage shape, like the kernel's."""
+    a = a[:d.shape[0], :v.shape[0]]
+    u = _mm(a.astype(jnp.float32), v.astype(jnp.float32))
     return _floored_degree_divide(u, d[:, None])
 
 
@@ -153,7 +165,7 @@ def affinity_matmat_ref(
                                    scale_r=scale_r, scale_c=scale_c, thr=thr)
     if thr_c is not None:
         a = jnp.where(a >= thr_c.astype(jnp.float32)[None, :], a, 0.0)
-    u = a @ v.astype(jnp.float32)
+    u = _mm(a, v.astype(jnp.float32))
     if d is None:
         return u
     return _floored_degree_divide(u, d[:, None])
@@ -183,7 +195,7 @@ def affinity_degree_streaming_ref(
 def gram_ref(v: jax.Array) -> jax.Array:
     """Oracle for kernels.gram.gram: G = VᵀV in f32."""
     v32 = v.astype(jnp.float32)
-    return v32.T @ v32
+    return _mm(v32.T, v32)
 
 
 def power_step_ref(a: jax.Array, v: jax.Array, d: jax.Array) -> jax.Array:
@@ -215,7 +227,7 @@ def kmeans_assign_ref(
     c = cents.astype(jnp.float32)
     xx = jnp.sum(x * x, axis=1, keepdims=True)
     cc = jnp.sum(c * c, axis=1)[None, :]
-    d2 = xx + cc - 2.0 * (x @ c.T)
+    d2 = xx + cc - 2.0 * _mm(x, c.T)
     return jnp.argmin(d2, axis=1).astype(jnp.int32), jnp.min(d2, axis=1)
 
 
@@ -273,7 +285,8 @@ def block_sparse_streaming_matmat_ref(
                                    row_offset=row_offset,
                                    col_offset=col_offset,
                                    scale_r=scale_r, scale_c=scale_c, thr=thr)
-    u = _apply_plan_ref(a, counts, col_idx, tm, tn) @ v.astype(jnp.float32)
+    u = _mm(_apply_plan_ref(a, counts, col_idx, tm, tn),
+            v.astype(jnp.float32))
     if d is None:
         return u
     return _floored_degree_divide(u, d[:, None])

@@ -44,6 +44,7 @@ from .affinity import (
     policy_specs_and_operands,
     unpack_policy_refs,
 )
+from .tuning import MXU_PRECISION
 
 STATS = ("similarity", "neg_sqdist")
 
@@ -85,7 +86,8 @@ def _row_topk_kernel(
     xr = xr_ref[...]
     xc = xc_ref[...]
     dot = jax.lax.dot_general(
-        xr, xc, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        xr, xc, (((1,), (1,)), ((), ())),
+        precision=MXU_PRECISION, preferred_element_type=jnp.float32
     )
 
     if stat == "similarity":

@@ -55,6 +55,7 @@ from .affinity import (
     tile_masks,
     unpack_policy_refs,
 )
+from .tuning import MXU_PRECISION
 
 
 def _masked_tile(i, j, off_ref, xr_ref, xc_ref, sqr_ref, sqc_ref,
@@ -70,7 +71,8 @@ def _masked_tile(i, j, off_ref, xr_ref, xc_ref, sqr_ref, sqc_ref,
     xr = xr_ref[...]                   # (TM, m) row slab
     xc = xc_ref[...]                   # (TN, m) col slab
     dot = jax.lax.dot_general(
-        xr, xc, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        xr, xc, (((1,), (1,)), ((), ())),
+        precision=MXU_PRECISION, preferred_element_type=jnp.float32
     )                                  # (TM, TN) affinity tile on the MXU
 
     a = affinity_tile_transform(
@@ -115,7 +117,8 @@ def _streaming_kernel(
 
     v = v_ref[...]                     # (TN, r) slice of V
     partial = jax.lax.dot_general(
-        a, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        a, v, (((1,), (0,)), ((), ())),
+        precision=MXU_PRECISION, preferred_element_type=jnp.float32
     )                                  # (TM, r)
 
     @pl.when(j == 0)

@@ -1,4 +1,5 @@
-"""Tile-size selection for the GPIC Pallas kernels (DESIGN.md §6).
+"""Tile-size and matmul-precision policy of the GPIC Pallas kernels
+(DESIGN.md §6).
 
 The kernels are tiled over a (n/TM, n/TN) grid; the tile size trades
 MXU utilization (bigger is better) against VMEM footprint and padding
@@ -9,6 +10,24 @@ from inside a ``jax.jit`` region on traced arrays' ``.shape``.
 from __future__ import annotations
 
 import math
+
+import jax
+import jax.numpy as jnp
+
+#: contraction precision of every kernel ``dot_general`` (DESIGN.md §6):
+#: f32 passes on the MXU. Every GPIC contraction is skinny — K = m for the
+#: affinity tiles, N = r for the sweeps, Gram and k-means — so the extra
+#: passes cost little against the tile's HBM or VPU work, while the rbf
+#: distance ``|x|² + |c|² − 2x·c`` cancels and the 1e-5/n convergence test
+#: both need f32 products.
+MXU_PRECISION = jax.lax.Precision.HIGHEST
+
+
+def f32_matmul(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a @ b`` at :data:`MXU_PRECISION` — the jnp products outside the
+    kernels that results rest on: the references, the matrix-free sweep,
+    Gram and k-means updates."""
+    return jnp.matmul(a, b, precision=MXU_PRECISION)
 
 #: candidate square tile edges, largest first (multiples of the 128-lane
 #: MXU/VPU width; 8-sublane aligned for f32, 16 for bf16).

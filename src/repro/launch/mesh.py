@@ -9,6 +9,7 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
+from jax.sharding import AxisType
 
 from ..configs.base import ModelConfig, ShapeCell
 
@@ -24,12 +25,8 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"production mesh needs {n} devices, have {len(devs)} — the "
             "dry-run entry point sets XLA_FLAGS=--xla_force_host_platform_"
             "device_count=512 before importing jax")
-    try:  # AxisType landed in jax 0.5; older jax defaults to Auto anyway
-        from jax.sharding import AxisType
-        kw = {"axis_types": (AxisType.Auto,) * len(axes)}
-    except ImportError:
-        kw = {}
-    return jax.make_mesh(shape, axes, devices=devs[:n], **kw)
+    return jax.make_mesh(shape, axes, devices=devs[:n],
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def _div(n: int, by: int) -> bool:
