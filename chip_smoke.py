@@ -18,12 +18,15 @@ One chip:
 - paper phase, Experiment II at n = 45,000 (``cassini``, ``gaussians``,
   ``shapes``, ``smiley``, ``max_iter=400``): the explicit engine with f32 A,
   the streaming engine, and one explicit run with bf16 A on ``gaussians``.
-  Explicit and streaming labels must agree at ARI >= 0.99.
+  The streaming and the bf16 labels must agree with the explicit f32 ones
+  at ARI >= 0.99, and every run must reach :data:`TRUTH_FLOOR` against
+  the ground truth.
 
 Four chips (``--four-chips``, only this phase): the sharded streaming ring
 and the sharded explicit engine on a 4-device mesh at n = 45,000
 (``gaussians``, ``smiley``), each compared with the one-chip run of the
-same config: ARI >= 0.99 and ``n_iter`` within :data:`N_ITER_TOL`.
+same config: ARI >= 0.99, ``n_iter`` within :data:`N_ITER_TOL`, and
+:data:`TRUTH_FLOOR` against the ground truth.
 
 Any failure exits non-zero: no TPU, interpret mode, a non-finite embedding,
 an unusable health report, a kernel that fell back to its jnp reference,
@@ -62,8 +65,19 @@ EMB_TOL = 1e-3
 #: allowed |n_iter(4 chips) - n_iter(1 chip)|. The stop test compares the
 #: per-sweep change with eps = 1e-5/n, close to f32 resolution, so the
 #: order in which stripes and ring stages sum can move the crossing by a
-#: few sweeps; 20 sweeps is 5% of max_iter.
-N_ITER_TOL = 20
+#: sweep or two: on a v5e the 4-chip ring stopped one sweep after one chip
+#: (gaussians, 16 vs 15) and the engines two after pic_reference (smiley
+#: at n = 4,096, 34 vs 32). Runs stop after 15-43 sweeps, so 4 leaves room
+#: for that and still refuses a sharded run that needs many more sweeps.
+N_ITER_TOL = 4
+
+#: least label ARI against the ground truth at n = 45,000. gaussians, shapes
+#: and smiley are separated exactly by this configuration (ARI 1.0 on a
+#: v5e); PIC's embedding splits cassini's three lobes only in part (0.653
+#: on a v5e, the same on both engines), so its floor is set below that
+#: reading and above a collapse into fewer clusters.
+TRUTH_FLOOR = {"cassini": 0.6, "gaussians": 0.99, "shapes": 0.99,
+               "smiley": 0.99}
 
 
 def fail(msg: str) -> None:
@@ -93,12 +107,17 @@ def peak_bytes(dev) -> int:
     return int(dev.memory_stats()["peak_bytes_in_use"])
 
 
-def memory_report(phase, devs) -> None:
+def memory_report(phase, devs, clock) -> None:
     """Every allocator counter of each device, once per phase: the per-run
     ``peak_bytes_in_use`` lines are process-wide peaks, and what each
     counter covers (arrays only, or program temporaries too) depends on
-    the runtime, so the whole record is printed."""
-    report(phase=phase, memory_stats=[dev.memory_stats() for dev in devs])
+    the runtime, so the whole record is printed. With it, the persistent
+    compile cache's hits and misses so far, and the seconds spent so far
+    in each compile stage (summed per event: a trace nested in another
+    counts in both)."""
+    report(phase=phase, memory_stats=[dev.memory_stats() for dev in devs],
+           compile_cache=dict(clock.cache),
+           compile_stage_s=dict(clock.stage_s))
 
 
 class CompileClock:
@@ -108,18 +127,28 @@ class CompileClock:
     union: a run's compile time is the union inside the call, its run
     time the rest of the call's wall time."""
 
-    EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-              "/jax/core/compile/jaxpr_to_mlir_module_duration",
-              "/jax/core/compile/backend_compile_duration")
+    EVENTS = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+              "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+              "/jax/core/compile/backend_compile_duration": "backend"}
+    CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hits",
+                    "/jax/compilation_cache/cache_misses": "misses"}
 
     def __init__(self, jax):
         self.spans: list[tuple[float, float]] = []
+        self.cache = {"hits": 0, "misses": 0}
+        self.stage_s = dict.fromkeys(self.EVENTS.values(), 0.0)
         jax.monitoring.register_event_duration_secs_listener(self._on)
+        jax.monitoring.register_event_listener(self._on_event)
 
     def _on(self, event, duration, **_):
         if event in self.EVENTS:
             end = time.perf_counter()
             self.spans.append((end - duration, end))
+            self.stage_s[self.EVENTS[event]] += duration
+
+    def _on_event(self, event, **_):
+        if event in self.CACHE_EVENTS:
+            self.cache[self.CACHE_EVENTS[event]] += 1
 
     def seconds_since(self, t0: float) -> float:
         total, reach = 0.0, t0
@@ -155,6 +184,12 @@ def check_result(res, tag):
         fail(f"{tag}: kernel fell back to its reference: {notes}")
     if ops.kernel_fallbacks():
         fail(f"{tag}: kernel fallbacks recorded: {ops.kernel_fallbacks()}")
+
+
+def check_truth(tag, name, agree) -> None:
+    if agree < TRUTH_FLOOR[name]:
+        fail(f"{tag}: label ARI vs ground truth {agree} < "
+             f"{TRUTH_FLOOR[name]}")
 
 
 def ari(a, b) -> float:
@@ -204,7 +239,7 @@ def reference_phase(jax, clock):
             if not err <= EMB_TOL:
                 fail(f"{tag}: embedding differs from pic_reference by "
                      f"{err} (relative) > {EMB_TOL}")
-    memory_report("reference", [dev])
+    memory_report("reference", [dev], clock)
 
 
 def paper_phase(jax, clock):
@@ -234,16 +269,15 @@ def paper_phase(jax, clock):
                           run_s=run_s, n_iter=int(res.n_iter),
                           ari_truth=ari(y, res.labels),
                           peak_bytes_in_use=peak_bytes(dev))
-            if engine == "streaming" or a_dtype == "bfloat16":
+            if (engine, a_dtype) != ("explicit", "float32"):
                 fields["ari_vs_explicit_f32"] = ari(
                     labels[("explicit", "float32")], res.labels)
             report(**fields)
-        agree = ari(labels[("explicit", "float32")],
-                    labels[("streaming", "float32")])
-        if agree < MIN_ARI:
-            fail(f"paper/{name}: explicit vs streaming label ARI {agree} "
-                 f"< {MIN_ARI}")
-    memory_report("paper", [dev])
+            check_truth(tag, name, fields["ari_truth"])
+            agree = fields.get("ari_vs_explicit_f32", 1.0)
+            if agree < MIN_ARI:
+                fail(f"{tag}: label ARI vs explicit f32 {agree} < {MIN_ARI}")
+    memory_report("paper", [dev], clock)
 
 
 def four_chip_phase(jax, clock):
@@ -270,13 +304,14 @@ def four_chip_phase(jax, clock):
             res, comp_s, run_s = timed_run(
                 jax, clock, xs, k, c.with_(mesh=mesh), jax.random.key(1))
             check_result(res, tag)
+            truth = ari(y, res.labels)
             report(phase="four_chips", dataset=name, engine=engine,
                    n=PAPER_N, rows_per_chip=PAPER_N // 4, compile_s=comp_s,
-                   run_s=run_s, n_iter=int(res.n_iter),
-                   ari_truth=ari(y, res.labels),
+                   run_s=run_s, n_iter=int(res.n_iter), ari_truth=truth,
                    peak_bytes_in_use=[peak_bytes(d) for d in devs])
+            check_truth(tag, name, truth)
             cases.append((tag, name, x, k, c, res))
-    memory_report("four_chips", devs)
+    memory_report("four_chips", devs, clock)
     for tag, name, x, k, c, res in cases:
         one, comp_s, run_s = timed_run(jax, clock, jnp.asarray(x), k, c,
                                        jax.random.key(1))

@@ -78,7 +78,7 @@ def fused_affinity_build(
     col_offset: jax.Array | int = 0,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """(A, D, thr) one-pass truncated build for the explicit engines — A at
-    its zero-padded storage shape (``ops.affinity_and_degree(padded=True)``)
+    its zero-padded storage shape (as ``ops.affinity_and_degree`` returns it)
     (DESIGN.md §13) — replaces pass 1b + the masked rebuild with ONE sweep
     over the feature blocks plus cheap epilogues:
 
@@ -115,7 +115,7 @@ def fused_affinity_build(
     a_raw, _ = ops.affinity_and_degree(
         x, xc, spec=spec, scale_r=scale_r, scale_c=scale_c, thr=None,
         tm=tm, tn=tn, out_dtype=jnp.float32,
-        row_offset=row_offset, col_offset=col_offset, padded=True,
+        row_offset=row_offset, col_offset=col_offset,
         force_reference=not use_pallas,
     )
     thr = topk_thresholds_from_scores(

@@ -237,7 +237,7 @@ def explicit_operator(inp, *, spec: AffinitySpec | None = None,
     scale, thr = affinity_stats(inp, spec, tile=tile, use_pallas=use_pallas)
     a, d = ops.affinity_and_degree(
         inp, spec=spec, scale_r=scale, scale_c=scale, thr=thr,
-        tm=tile, tn=tile, out_dtype=a_dtype, padded=True,
+        tm=tile, tn=tile, out_dtype=a_dtype,
         force_reference=not use_pallas,
     )
 
@@ -449,7 +449,7 @@ def sharded_explicit_operator(x_loc, *, axes,
     a_loc, d_raw = ops.affinity_and_degree(
         x_loc, x_full, kind=build_kind, sigma=spec.sigma,
         scale_r=scale_loc, scale_c=scale_full, thr=thr_loc,
-        tm=tile, tn=tile, out_dtype=a_dtype, row_offset=row0, padded=True,
+        tm=tile, tn=tile, out_dtype=a_dtype, row_offset=row0,
         force_reference=not use_pallas,
     )
 

@@ -177,7 +177,9 @@ def pic_from_affinity(
     ONE (n, r) batched state — a single W mat-mat per iteration instead of
     r separate sweeps (core/power.py). ``embedding`` selects the block mode
     ('pic' | 'orthogonal' | 'ensemble', DESIGN.md §10); this oracle path
-    runs the block algebra through the bare ``w @ V`` operator (jnp Gram).
+    runs the block algebra through the bare ``w @ V`` operator (jnp Gram)
+    and k-means through the jnp assignment oracle, so no Pallas kernel is
+    on this path: it is a reference independent of the kernels under test.
     """
     n = a.shape[0]
     if eps is None:
@@ -196,7 +198,8 @@ def pic_from_affinity(
         qr_every=qr_every, snapshot_iters=snapshot_iters,
         residual_tol=residual_tol)
     emb = standardize_columns(emb_raw)
-    labels, _cent = kmeans(kkm, emb, k, iters=kmeans_iters)
+    labels, _cent = kmeans(kkm, emb, k, iters=kmeans_iters,
+                           force_reference=True)
     health = HealthReport(
         col_status=status, isolated_rows=count_bad_rows(d),
         n_components=jnp.int32(-1),        # no spec here — probe not armed

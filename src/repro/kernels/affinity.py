@@ -203,8 +203,7 @@ def affinity_and_degree(
     A comes at its padded storage shape (R, C rounded up to tm, tn
     multiples); pad rows and columns are masked to exact zeros, so the
     sweeps consume the array as it is and no (R, C) copy is ever sliced
-    out of it (``kernels.ops.affinity_and_degree`` slices for callers that
-    want the logical shape).
+    out of it.
 
     ``xc=None`` is the square self-affinity (the paper's build): A is
     (n, n) and D its row sums. With ``xc`` given, A is the

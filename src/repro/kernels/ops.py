@@ -252,7 +252,7 @@ def _spec_kind_sigma(spec, kind: str, sigma: float) -> tuple[str, float]:
 def affinity_and_degree(xn, xc=None, *, kind="cosine_shifted", sigma=1.0,
                         spec=None, scale_r=None, scale_c=None, thr=None,
                         tm=None, tn=None, out_dtype=jnp.float32,
-                        row_offset=0, col_offset=0, padded=False,
+                        row_offset=0, col_offset=0,
                         force_reference=False, mode=None):
     """Fused A + D build (paper kernels 1-2). See kernels/affinity.py.
 
@@ -265,10 +265,10 @@ def affinity_and_degree(xn, xc=None, *, kind="cosine_shifted", sigma=1.0,
     (per-row truncation thresholds) realize its policies in-tile
     (DESIGN.md §11).
 
-    ``padded=True`` returns A at its storage shape — rows and columns
-    rounded up to the tile multiples, the pad entries exact zeros — which
-    the sweep kernels consume without a copy: the engines store ONE
-    (n, n) array. The default slices out the logical (R, C) matrix.
+    A comes at its storage shape — rows and columns rounded up to the tile
+    multiples, the pad entries exact zeros — in every mode, and the sweep
+    kernels consume it without a copy: the engines store ONE (n, n) array.
+    The logical matrix is ``a[:R, :C]``.
     """
     kind, sigma = _spec_kind_sigma(spec, kind, sigma)
     mode = _resolve_mode(mode, force_reference)
@@ -283,21 +283,18 @@ def affinity_and_degree(xn, xc=None, *, kind="cosine_shifted", sigma=1.0,
             row_offset=row_offset, col_offset=col_offset,
             scale_r=scale_r, scale_c=scale_c, thr=thr)
         a = a.astype(out_dtype)           # honor O4 storage dtype here too
-        if padded:
-            a = jnp.pad(a, ((0, -(-n_rows // tm_) * tm_ - n_rows),
-                            (0, -(-n_cols // tn_) * tn_ - n_cols)))
-        return a, deg
+        return jnp.pad(a, ((0, -(-n_rows // tm_) * tm_ - n_rows),
+                           (0, -(-n_cols // tn_) * tn_ - n_cols))), deg
 
     if mode == "reference":
         return _ref()
-    a, deg = _guarded("affinity_and_degree", lambda: dispatch(
+    return _guarded("affinity_and_degree", lambda: dispatch(
         "affinity_and_degree", mode)(
         xn, xc, kind=kind, sigma=sigma, tm=tm_, tn=tn_, out_dtype=out_dtype,
         row_offset=row_offset, col_offset=col_offset,
         scale_r=scale_r, scale_c=scale_c, thr=thr,
         interpret=_interpret(),
     ), _ref)
-    return (a, deg) if padded else (a[:n_rows, :n_cols], deg)
 
 
 def degree_normalized_matvec(a, v, d, *, tm=None, tn=None,
@@ -319,9 +316,9 @@ def degree_normalized_matmat(a, v, d, *, tm=None, tn=None,
 
     ``a`` may be a rectangular (R, C) row stripe of the global matrix (the
     sharded explicit path, DESIGN.md §9); d is the stripe's (R,) degrees.
-    ``a`` may also come at the padded storage shape of
-    ``affinity_and_degree(..., padded=True)``; tiles are resolved from the
-    logical shape (len(d), len(v)) either way.
+    ``a`` may also come at the padded storage shape that
+    ``affinity_and_degree`` returns; tiles are resolved from the logical
+    shape (len(d), len(v)) either way.
     """
     mode = _resolve_mode(mode, force_reference)
     if mode == "reference":

@@ -291,7 +291,7 @@ class TestTwoPassMaskedBuild:
         a_k, d_k = ops.affinity_and_degree(inp, spec=spec, scale_r=scale,
                                            scale_c=scale, thr=thr)
         a_ref = affinity_matrix(inp, spec=spec)
-        np.testing.assert_allclose(a_k, a_ref, atol=1e-5)
+        np.testing.assert_allclose(a_k[:n, :n], a_ref, atol=1e-5)
         np.testing.assert_allclose(d_k, jnp.sum(a_ref, axis=1),
                                    atol=1e-3, rtol=1e-5)
 

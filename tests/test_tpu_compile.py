@@ -4,7 +4,8 @@ No chip is needed: the TPU compiler is installed, and it compiles for a
 chip that is described (``v5e:2x2``) but not attached. What interpret mode
 cannot see shows up here — tiles Mosaic refuses, VMEM overruns, a program
 that does not fit HBM. Shapes are the paper's Experiment II (n = 45,000,
-m = 2) with the tiles ``kernels/tuning.py`` picks there.
+m = 2) with the tiles ``kernels/tuning.py`` picks there; the sharded
+programs run on a mesh of the four described chips (11,250 rows each).
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and a test worker that imported this
@@ -12,7 +13,9 @@ file without running it must not take it.
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.affinity import block_plan
@@ -30,13 +33,22 @@ N, M, R, K = 45_000, 2, 2, 4
 TILE, _ = choose_tiles(N, r=R, m=M)
 NP = -(-N // TILE) * TILE                 # padded storage edge of A
 
-#: compiled temp memory of gpic(engine='explicit', f32 A) at n = 45,000:
-#: one stored A is 8.12 GB (45,056² · 4 B); two copies were 16.30 GB
-EXPLICIT_F32_TEMP_BOUND = 9.0e9
+#: compiled temp memory of gpic(engine='explicit') at n = 45,000: one
+#: stored A is 8.12 GB in f32 (45,056² · 4 B) and 4.06 GB in bf16; two
+#: copies were 16.30 and 8.16 GB
+EXPLICIT_TEMP_BOUND = {jnp.float32: 9.0e9, jnp.bfloat16: 4.6e9}
+#: the streaming engine stores no A: its temporaries are O(n) vectors and
+#: tiles, far below the 4.06 GB of even a bf16 (n, n) array
+STREAMING_TEMP_BOUND = 0.5e9
+#: per-chip temp of the sharded explicit engine on 4 chips: one f32 row
+#: stripe of A, 11,264 × 45,056 · 4 B = 2.03 GB (two copies would be 4.06)
+CHIPS = 4
+STRIPE = -(-(N // CHIPS) // TILE) * TILE
+SHARDED_TEMP_BOUND = {"explicit": 2.3e9, "streaming": STREAMING_TEMP_BOUND}
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_devices():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     with pytest.MonkeyPatch.context() as mp:
@@ -51,9 +63,14 @@ def one_chip():
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo.devices
     jax.config.update("jax_enable_compilation_cache", prev)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_devices):
+    return SingleDeviceSharding(v5e_devices[0])
 
 
 def _spec(one_chip, shape, dtype=jnp.float32):
@@ -105,22 +122,59 @@ def test_block_plan_feeds_scalar_prefetch(one_chip):
         _spec(one_chip, (N, R)), _spec(one_chip, (N,))))
 
 
-def test_whole_gpic_stores_one_copy_of_a(one_chip, monkeypatch):
-    """The whole jitted ``gpic`` (rbf, 400 sweeps, f32 A) compiles for one
-    v5e, and its temp memory is one stored A, not two."""
+def _compiled_gpic_temp(monkeypatch, sharding, **kw) -> int:
+    """Compile the whole jitted ``gpic`` (rbf, 400 sweeps) for the
+    described chip; returns its temp memory in bytes."""
     from repro.core.gpic import gpic
     # the kernels pick interpret mode from the backend JAX runs on; this
     # compile targets the described chip, so the test steers it
     monkeypatch.setattr(ops, "_INTERPRET", False)
     key = jax.eval_shape(lambda: jax.random.key(0))
     compiled = gpic.lower(
-        _spec(one_chip, (N, M)), K,
-        key=_spec(one_chip, key.shape, key.dtype),
-        affinity_kind="rbf", sigma=0.3, max_iter=400,
-        engine="explicit").compile()
+        _spec(sharding, (N, M)), K,
+        key=_spec(sharding, key.shape, key.dtype),
+        affinity_kind="rbf", sigma=0.3, max_iter=400, **kw).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled.memory_analysis().temp_size_in_bytes
+
+
+@pytest.mark.parametrize("a_dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_whole_gpic_stores_one_copy_of_a(one_chip, monkeypatch, a_dtype):
+    """The explicit engine compiles for one v5e, and its temp memory is one
+    stored A, not two."""
+    temp = _compiled_gpic_temp(monkeypatch, one_chip, engine="explicit",
+                               a_dtype=a_dtype)
+    lower = NP * NP * jnp.dtype(a_dtype).itemsize
+    assert lower <= temp <= EXPLICIT_TEMP_BOUND[a_dtype], temp
+
+
+def test_whole_gpic_streaming_stores_no_a(one_chip, monkeypatch):
+    temp = _compiled_gpic_temp(monkeypatch, one_chip, engine="streaming")
+    assert temp <= STREAMING_TEMP_BOUND, temp
+
+
+@pytest.mark.parametrize("engine", ["explicit", "streaming"])
+def test_sharded_gpic_compiles_for_four_chips(v5e_devices, monkeypatch,
+                                              engine):
+    """``distributed_gpic`` on a mesh of the four described chips: the
+    ring (streaming) or one A stripe per chip (explicit), each chip's
+    temp memory its own share."""
+    from repro.core.distributed import distributed_gpic
+    monkeypatch.setattr(ops, "_INTERPRET", False)
+    mesh = Mesh(np.asarray(v5e_devices[:CHIPS]), ("data",))
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    compiled = distributed_gpic.lower(
+        jax.ShapeDtypeStruct((N, M), jnp.float32,
+                             sharding=NamedSharding(mesh, P("data"))),
+        K, key=jax.ShapeDtypeStruct(key.shape, key.dtype,
+                                    sharding=NamedSharding(mesh, P())),
+        mesh=mesh, affinity_kind="rbf", sigma=0.3, max_iter=400,
+        engine=engine).compile()
     assert "tpu_custom_call" in compiled.as_text()
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert NP * NP * 4 <= temp <= EXPLICIT_F32_TEMP_BOUND, temp
+    lower = STRIPE * NP * 4 if engine == "explicit" else 0
+    assert lower <= temp <= SHARDED_TEMP_BOUND[engine], temp
 
 
 def test_tile_is_the_autotuners_choice():
