@@ -31,6 +31,8 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 
+from .spans import SYNC, span
+
 
 # ---------------------------------------------------------------------------
 # Typed errors
@@ -360,7 +362,9 @@ def validate_features(x, k: int, *, sanitize: bool = False):
     if isinstance(x, jax.core.Tracer):
         return x, tuple(notes)
     x = jnp.asarray(x)
-    n_bad = int(jnp.sum(~jnp.isfinite(x)))
+    bad = jnp.sum(~jnp.isfinite(x))
+    with span(SYNC):
+        n_bad = int(bad)
     if n_bad:
         if not sanitize:
             raise NonFiniteInputError(
@@ -371,7 +375,10 @@ def validate_features(x, k: int, *, sanitize: bool = False):
     # per-column extrema instead of comparing against row 0: a reduction
     # needs no indexing, so it also runs on a row-sharded array whose mesh
     # axes are Explicit (the default of ``jax.make_mesh``)
-    if bool(jnp.all(jnp.max(x, axis=0) == jnp.min(x, axis=0))):
+    same = jnp.all(jnp.max(x, axis=0) == jnp.min(x, axis=0))
+    with span(SYNC):
+        constant = bool(same)
+    if constant:
         raise InvalidInputError(
             "all feature rows are identical — every pairwise affinity is "
             "equal and the power embedding is constant; clustering is "
@@ -386,12 +393,14 @@ def raise_for_health(health: HealthReport, n: int) -> None:
     if isinstance(health.col_status, jax.core.Tracer):
         return
     import numpy as np
-    iso = int(health.isolated_rows)
+    with span(SYNC):
+        iso = int(health.isolated_rows)
     if iso >= n:
         raise DegenerateGraphError(
             f"every one of the {n} rows is isolated (zero degree) — the "
             "affinity graph is empty; widen sigma / raise knn_k")
-    status = np.asarray(health.col_status)
+    with span(SYNC):
+        status = np.asarray(health.col_status)
     fatal = COL_NONFINITE | COL_ZERO
     if status.size and bool(((status & fatal) != 0).all()):
         names = [describe_status(c) for c in status.tolist()]

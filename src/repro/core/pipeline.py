@@ -29,6 +29,7 @@ Routing table (operator names from core/operators.py):
 """
 from __future__ import annotations
 
+import itertools
 import os
 import time
 from dataclasses import dataclass, replace
@@ -39,6 +40,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ..kernels import ops
+from . import spans
 from .affinity import (
     AffinityKind,
     AffinitySpec,
@@ -75,6 +77,9 @@ from .pic import PICResult
 from .power import EMBEDDINGS, default_snapshot_iters, power_carry_like
 
 ENGINES = ("explicit", "streaming", "matrix_free")
+#: numbers the calls of ``run_gpic`` in this process: the metadata of each
+#: call's span, shared by the spans of one job
+_CALLS = itertools.count()
 
 
 @dataclass(frozen=True)
@@ -273,9 +278,100 @@ def run_gpic(
     through the segmented engines; the trajectory stays bitwise identical
     to the monolithic path (DESIGN.md §14).
     """
-    cfg = config or GPICConfig()
-    if overrides:
-        cfg = cfg.with_(**overrides)
+    with spans.span(spans.RUN, call=next(_CALLS)):
+        with spans.span(spans.FRONT_DOOR):
+            cfg = config or GPICConfig()
+            if overrides:
+                cfg = cfg.with_(**overrides)
+            spec = _check_config(cfg, x.shape[0])
+            if key is None:
+                key = jax.random.key(cfg.seed)
+            x, health_notes, inv = _prepare_features(x, k, cfg, spec)
+        fallbacks_before = ops.kernel_fallbacks()
+
+        snapshot_iters = (None if cfg.snapshot_iters is None
+                          else tuple(cfg.snapshot_iters))
+        common = dict(key=key, max_iter=cfg.max_iter,
+                      kmeans_iters=cfg.kmeans_iters,
+                      affinity=spec, n_vectors=cfg.n_vectors,
+                      embedding=cfg.embedding, qr_every=cfg.qr_every,
+                      snapshot_iters=snapshot_iters,
+                      residual_tol=cfg.residual_tol)
+
+        def _route(c: GPICConfig) -> PICResult:
+            if c.mesh is None:
+                if c.engine == "matrix_free":
+                    return gpic_matrix_free(
+                        x, k, eps=c.eps_scale / x.shape[0],
+                        use_pallas=c.use_pallas, **common)
+                return gpic(
+                    x, k, engine=c.engine, a_dtype=c.a_dtype,
+                    tile=c.tile, use_pallas=c.use_pallas,
+                    block_sparse=c.block_sparse,
+                    eps=c.eps_scale / x.shape[0],
+                    probe_components=c.component_probe, **common)
+            shard_axes = (c.shard_axes if isinstance(c.shard_axes, str)
+                          else tuple(c.shard_axes))
+            if c.engine == "matrix_free":
+                return distributed_gpic_matrix_free(
+                    x, k, mesh=c.mesh, shard_axes=shard_axes,
+                    eps_scale=c.eps_scale, use_pallas=c.use_pallas, **common)
+            return distributed_gpic(
+                x, k, mesh=c.mesh, shard_axes=shard_axes,
+                engine=c.engine, eps_scale=c.eps_scale,
+                a_dtype=c.a_dtype, fold_shift=c.fold_shift,
+                tile=c.tile, use_pallas=c.use_pallas,
+                block_sparse=c.block_sparse, overlap=c.overlap,
+                probe_components=c.component_probe,
+                inject_ring_fault=c.inject_ring_fault, **common)
+
+        supervised = (cfg.checkpoint_every is not None
+                      or cfg.straggler_timeout is not None
+                      or segment_injector is not None)
+        if supervised:
+            # the resumable path handles fallback classification itself
+            # (it must not save a kernel/reference-mixed segment)
+            res, sup_notes = _run_supervised(
+                x, k, cfg, key=key, spec=spec,
+                segment_injector=segment_injector)
+        else:
+            with spans.span(spans.LAUNCH):
+                res = _route(cfg)
+        with spans.span(spans.HEALTH):
+            if supervised:
+                notes = tuple(health_notes) + sup_notes
+            else:
+                # attach host-side events (kernel fallbacks that first
+                # fired during this run)
+                new_fallback_ops = tuple(sorted(
+                    op for op in ops.kernel_fallbacks()
+                    if op not in fallbacks_before))
+                note_tag = "kernel_fallback"
+                if (new_fallback_ops and cfg.retry_on_fallback
+                        and cfg.use_pallas):
+                    # a mid-run fallback leaves a MIXED kernel/reference
+                    # trajectory (only the ops that failed were served by
+                    # their oracles); re-run the whole pipeline on the
+                    # reference oracles so every sweep of the reported
+                    # result came from ONE consistent implementation
+                    with spans.span(spans.LAUNCH):
+                        res = _route(cfg.with_(use_pallas=False))
+                    note_tag = "kernel_fallback_retried"
+                notes = tuple(health_notes) + tuple(
+                    f"{note_tag}:{op}" for op in new_fallback_ops)
+            if inv is not None:
+                res = _unpermute_result(res, inv)
+            if res.health is not None and notes:
+                res = replace(res, health=replace(
+                    res.health, notes=res.health.notes + notes))
+            if res.health is not None:
+                raise_for_health(res.health, x.shape[0])
+    return res
+
+
+def _check_config(cfg: GPICConfig, n: int) -> AffinitySpec:
+    """Reject unknown or contradictory field combinations; returns the
+    resolved affinity spec."""
     if cfg.engine not in ENGINES:
         raise ValueError(
             f"unknown engine {cfg.engine!r} (expected one of {ENGINES})")
@@ -319,7 +415,7 @@ def run_gpic(
             "affinity_kind/sigma shorthand, not both")
     spec = as_affinity_spec(cfg.affinity, kind=cfg.affinity_kind,
                             sigma=cfg.sigma)
-    spec.validate_for_n(x.shape[0])
+    spec.validate_for_n(n)
     # reject field combinations the selected route would silently ignore —
     # the front door must not mask misconfiguration a direct call rejects
     if cfg.engine == "matrix_free":
@@ -371,9 +467,13 @@ def run_gpic(
         raise ValueError(
             "inject_ring_fault poisons a sharded streaming ring stage; it "
             "needs mesh set and engine='streaming'")
-    if key is None:
-        key = jax.random.key(cfg.seed)
+    return spec
 
+
+def _prepare_features(x, k, cfg: GPICConfig, spec: AffinitySpec):
+    """Validate the features and, under ``row_reorder``, put the rows in
+    canonical order. Returns (x, health notes, the inverse permutation or
+    None)."""
     # front-door input validation (typed errors; value checks skip under
     # a tracer and the device-side latches carry the load)
     x, health_notes = validate_features(x, k, sanitize=cfg.sanitize)
@@ -397,79 +497,7 @@ def run_gpic(
             x = jax.device_put(
                 x, NamedSharding(cfg.mesh, PartitionSpec(shard_axes)))
         health_notes = tuple(health_notes) + ("row_reorder",)
-    fallbacks_before = ops.kernel_fallbacks()
-
-    snapshot_iters = (None if cfg.snapshot_iters is None
-                      else tuple(cfg.snapshot_iters))
-    common = dict(key=key, max_iter=cfg.max_iter,
-                  kmeans_iters=cfg.kmeans_iters,
-                  affinity=spec, n_vectors=cfg.n_vectors,
-                  embedding=cfg.embedding, qr_every=cfg.qr_every,
-                  snapshot_iters=snapshot_iters,
-                  residual_tol=cfg.residual_tol)
-
-    def _route(c: GPICConfig) -> PICResult:
-        if c.mesh is None:
-            if c.engine == "matrix_free":
-                return gpic_matrix_free(x, k, eps=c.eps_scale / x.shape[0],
-                                        use_pallas=c.use_pallas, **common)
-            return gpic(
-                x, k, engine=c.engine, a_dtype=c.a_dtype,
-                tile=c.tile, use_pallas=c.use_pallas,
-                block_sparse=c.block_sparse,
-                eps=c.eps_scale / x.shape[0],
-                probe_components=c.component_probe, **common)
-        shard_axes = (c.shard_axes if isinstance(c.shard_axes, str)
-                      else tuple(c.shard_axes))
-        if c.engine == "matrix_free":
-            return distributed_gpic_matrix_free(
-                x, k, mesh=c.mesh, shard_axes=shard_axes,
-                eps_scale=c.eps_scale, use_pallas=c.use_pallas, **common)
-        return distributed_gpic(
-            x, k, mesh=c.mesh, shard_axes=shard_axes,
-            engine=c.engine, eps_scale=c.eps_scale,
-            a_dtype=c.a_dtype, fold_shift=c.fold_shift,
-            tile=c.tile, use_pallas=c.use_pallas,
-            block_sparse=c.block_sparse, overlap=c.overlap,
-            probe_components=c.component_probe,
-            inject_ring_fault=c.inject_ring_fault, **common)
-
-    supervised = (cfg.checkpoint_every is not None
-                  or cfg.straggler_timeout is not None
-                  or segment_injector is not None)
-    if supervised:
-        # the resumable path handles fallback classification itself (it
-        # must not save a kernel/reference-mixed segment)
-        res, sup_notes = _run_supervised(
-            x, k, cfg, key=key, spec=spec,
-            segment_injector=segment_injector)
-        notes = tuple(health_notes) + sup_notes
-    else:
-        res = _route(cfg)
-        # attach host-side events (kernel fallbacks that first fired
-        # during this run)
-        new_fallback_ops = tuple(sorted(
-            op for op in ops.kernel_fallbacks()
-            if op not in fallbacks_before))
-        note_tag = "kernel_fallback"
-        if new_fallback_ops and cfg.retry_on_fallback and cfg.use_pallas:
-            # a mid-run fallback leaves a MIXED kernel/reference trajectory
-            # (only the ops that failed were served by their oracles);
-            # re-run the whole pipeline on the reference oracles so every
-            # sweep of the reported result came from ONE consistent
-            # implementation
-            res = _route(cfg.with_(use_pallas=False))
-            note_tag = "kernel_fallback_retried"
-        notes = tuple(health_notes) + tuple(
-            f"{note_tag}:{op}" for op in new_fallback_ops)
-    if inv is not None:
-        res = _unpermute_result(res, inv)
-    if res.health is not None and notes:
-        res = replace(res, health=replace(
-            res.health, notes=res.health.notes + notes))
-    if res.health is not None:
-        raise_for_health(res.health, x.shape[0])
-    return res
+    return x, health_notes, inv
 
 
 def _row_reorder_permutation(x, cfg: GPICConfig, spec: AffinitySpec):
@@ -562,7 +590,6 @@ def _run_supervised(x, k, cfg: GPICConfig, *, key, spec, segment_injector):
     """
     # train imports core at module load; import lazily to avoid the cycle
     from ..train import checkpoint as ckpt
-    from ..train.fault_tolerance import StragglerMonitor
 
     n = x.shape[0]
     mode, qr_every, si, residual_tol = _segment_plan(cfg)
@@ -572,7 +599,6 @@ def _run_supervised(x, k, cfg: GPICConfig, *, key, spec, segment_injector):
     shard_axes = (cfg.shard_axes if isinstance(cfg.shard_axes, str)
                   else tuple(cfg.shard_axes))
     saver = ckpt.AsyncCheckpointer() if cfg.ckpt_dir is not None else None
-    monitor = StragglerMonitor()
     notes: list[str] = []
 
     def seg_kwargs(use_pallas):
@@ -640,7 +666,6 @@ def _run_supervised(x, k, cfg: GPICConfig, *, key, spec, segment_injector):
             jax.block_until_ready(carry.v)
             sec = time.monotonic() - t0
             t_after = int(jax.device_get(carry.t))
-            monitor.record(t_after, sec)
             if (cfg.straggler_timeout is not None
                     and sec > cfg.straggler_timeout):
                 notes.append(f"straggler:{t_after}:{sec:.3f}")

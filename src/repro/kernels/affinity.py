@@ -267,5 +267,6 @@ def affinity_and_degree(
             jax.ShapeDtypeStruct((rp, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="affinity_and_degree",
     )(*operands, *pol_ops)
     return a, d[:n_rows, 0]

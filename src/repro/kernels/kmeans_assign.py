@@ -73,5 +73,6 @@ def kmeans_assign(
             jax.ShapeDtypeStruct((n_pad, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="kmeans_assign",
     )(xp, cp, csq)
     return labels[:n, 0], dists[:n, 0]

@@ -118,6 +118,7 @@ def degree_normalized_matmat(
         out_specs=pl.BlockSpec((tm, r), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rp, r), jnp.float32),
         interpret=interpret,
+        name="degree_normalized_matmat",
     )(a, v.astype(jnp.float32), d.astype(jnp.float32)[:, None])
     return u[:n_rows]
 

@@ -234,6 +234,7 @@ def affinity_matmat(
         out_specs=pl.BlockSpec((tm, r), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rp, r), jnp.float32),
         interpret=interpret,
+        name="affinity_matmat",
     )(*operands, *pol_ops)
     return u[:n_rows]
 
@@ -340,5 +341,6 @@ def affinity_degree_streaming(
         out_specs=pl.BlockSpec((tm, 1), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rp, 1), jnp.float32),
         interpret=interpret,
+        name="affinity_degree_streaming",
     )(*operands, *pol_ops)
     return d[:n_rows, 0]

@@ -11,6 +11,10 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and a test worker that imported this
 file without running it must not take it.
 """
+import importlib.util
+import os
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -28,6 +32,9 @@ from repro.kernels.power_step import degree_normalized_matmat
 from repro.kernels.row_topk import row_topk
 from repro.kernels.streaming import affinity_degree_streaming, affinity_matmat
 from repro.kernels.tuning import choose_tiles
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)     # the benchmark's ``chipbench`` package
 
 N, M, R, K = 45_000, 2, 2, 4
 TILE, _ = choose_tiles(N, r=R, m=M)
@@ -181,3 +188,62 @@ def test_tile_is_the_autotuners_choice():
     """The shapes above are the ones the engines run at n = 45,000."""
     assert (TILE, NP) == (512, 45_056)
     assert ops.resolve_tiles(N, r=R, m=M) == (TILE, TILE)
+
+
+#: n of the subsample cell: the op names do not depend on n, and the
+#: programs compile in seconds there
+N_NAMES = 4_500
+
+
+def _reader_op_names(engine: str) -> set:
+    """The op names the benchmark's kernel readers
+    (``chipbench/metrics/*.kernel_ms.py``) key on for ``engine``."""
+    names = set()
+    for metric, attr in (("sweep.kernel_ms", "SWEEP_KERNEL"),
+                         ("build.kernel_ms", "BUILD_KERNEL"),
+                         ("kmeans.kernel_ms", "KMEANS_KERNEL")):
+        spec = importlib.util.spec_from_file_location(
+            "reader_" + metric.replace(".", "_"),
+            os.path.join(ROOT, "chipbench", "metrics", metric + ".py"))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        keyed = getattr(mod, attr)
+        names.add(keyed[engine] if isinstance(keyed, dict) else keyed)
+    return names
+
+
+def _hlo_ops(text: str) -> set:
+    """The op of every instruction in compiled HLO text, reduced as the
+    benchmark reduces a device event's name (``chipbench.trace.Event``)."""
+    from chipbench.trace import Event
+    return {Event(line.strip().removeprefix("ROOT "), 0.0, 0.0).op
+            for line in text.splitlines() if " = " in line}
+
+
+@pytest.mark.parametrize("program", ["explicit", "streaming",
+                                     "sharded-explicit"])
+def test_reader_op_names_are_in_the_compiled_program(
+        v5e_devices, one_chip, monkeypatch, program):
+    """Every kernel a benchmark reader times keeps its op name in the
+    program the cells run: a rename fails here, not silently on the
+    chip."""
+    from repro.core.distributed import distributed_gpic
+    from repro.core.gpic import gpic
+    monkeypatch.setattr(ops, "_INTERPRET", False)
+    key = jax.eval_shape(lambda: jax.random.key(0))
+    engine = program.rpartition("-")[2]
+    kw = dict(affinity_kind="rbf", sigma=0.3, max_iter=400, engine=engine)
+    if program.startswith("sharded"):
+        mesh = Mesh(np.asarray(v5e_devices[:CHIPS]), ("data",))
+        lowered = distributed_gpic.lower(
+            jax.ShapeDtypeStruct((N_NAMES, M), jnp.float32,
+                                 sharding=NamedSharding(mesh, P("data"))),
+            K, key=jax.ShapeDtypeStruct(key.shape, key.dtype,
+                                        sharding=NamedSharding(mesh, P())),
+            mesh=mesh, **kw)
+    else:
+        lowered = gpic.lower(
+            _spec(one_chip, (N_NAMES, M)), K,
+            key=_spec(one_chip, key.shape, key.dtype), **kw)
+    found = _hlo_ops(lowered.compile().as_text())
+    assert _reader_op_names(engine) <= found, sorted(found)
